@@ -8,17 +8,14 @@ the analytical simulator — same engine, different evaluator.
 Usage: python experiments/hillclimb_run.py <arch:shape> <variant>[,<variant>...]
 Results saved to experiments/hillclimb/<cell>_<variant>.json.
 """
+import dataclasses
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-import dataclasses, json, sys, time
+import sys
+import time
 
 from repro.core.study import Axis, StudySpec, run_study
-from repro.launch.dryrun import lower_cell
-
-CELL = sys.argv[1]          # e.g. internlm2-20b:train_4k
-NAMES = sys.argv[2].split(",")  # one or more variant names
-
-arch, shape = CELL.split(":")
+from repro.launch.dryrun import HOST_DEVICES_FLAG, lower_cell
 
 def remat_blocks(plan):
     return dataclasses.replace(plan, remat="blocks")
@@ -71,14 +68,7 @@ VARIANTS = {
 }
 
 
-unknown = [n for n in NAMES if n not in VARIANTS]
-if unknown:
-    sys.exit(f"unknown variant(s) {unknown}; available: {sorted(VARIANTS)}")
-
-os.makedirs("experiments/hillclimb", exist_ok=True)
-
-
-def _evaluate(ctx):
+def _evaluate(ctx, arch, shape):
     # Persist + report per variant as soon as it finishes: a crash in a
     # later variant must not discard earlier multi-minute dry-run results.
     variant = ctx.point["variant"]
@@ -98,6 +88,20 @@ def _evaluate(ctx):
     return info
 
 
-spec = StudySpec(name=f"hillclimb:{CELL}",
-                 axes=[Axis("variant", tuple(NAMES))], evaluate=_evaluate)
-run_study(spec)
+def main():
+    os.environ["XLA_FLAGS"] = HOST_DEVICES_FLAG
+    cell = sys.argv[1]                  # e.g. internlm2-20b:train_4k
+    names = sys.argv[2].split(",")      # one or more variant names
+    arch, shape = cell.split(":")
+    unknown = [n for n in names if n not in VARIANTS]
+    if unknown:
+        sys.exit(f"unknown variant(s) {unknown}; available: {sorted(VARIANTS)}")
+    os.makedirs("experiments/hillclimb", exist_ok=True)
+    spec = StudySpec(name=f"hillclimb:{cell}",
+                     axes=[Axis("variant", tuple(names))],
+                     evaluate=lambda ctx: _evaluate(ctx, arch, shape))
+    run_study(spec)
+
+
+if __name__ == "__main__":
+    main()

@@ -12,7 +12,8 @@ scans for the newest ``.done`` whose directory actually holds a
 ``meta.json``, falling back past stale markers left by an interrupted
 re-save; orphaned ``step_X.tmp`` buffers are GC'd on construction).
 ``save_async`` runs the serialization on a worker thread so the train
-loop only pays for the host transfer.
+loop only pays for the host transfer; ``wait`` re-raises whatever that
+thread raised, so a failed save fails the run.
 
 Elastic restore: leaves are stored unsharded; ``restore`` device_puts them
 under whatever shardings the *current* mesh dictates, so restarting on a
@@ -60,6 +61,7 @@ class Checkpointer:
         self.directory = directory
         os.makedirs(directory, exist_ok=True)
         self._thread: Optional[threading.Thread] = None
+        self._error: Optional[Exception] = None
         # GC orphaned write buffers from a previous crashed save: a
         # step_X.tmp dir is by construction uncommitted and unreadable.
         for name in os.listdir(directory):
@@ -113,14 +115,24 @@ class Checkpointer:
         host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)),
                                  tree)
         self.wait()
-        self._thread = threading.Thread(
-            target=self.save, args=(step, host_tree, extra), daemon=True)
+
+        def write():
+            try:
+                self.save(step, host_tree, extra)
+            except Exception as e:  # noqa: BLE001 — re-raised by wait()
+                self._error = e
+
+        self._thread = threading.Thread(target=write, daemon=True)
         self._thread.start()
 
     def wait(self) -> None:
+        """Block until the in-flight async save ends; re-raise its error."""
         if self._thread is not None:
             self._thread.join()
             self._thread = None
+        error, self._error = self._error, None
+        if error is not None:
+            raise error
 
     # ------------------------------------------------------------------ #
     def _committed_steps(self) -> List[int]:

@@ -5,7 +5,8 @@ ssd_scan        — Mamba2 SSD chunked scan (state carried in VMEM scratch)
 rmsnorm         — fused norm
 embedding_bag   — pooled DLRM lookups (explicit-DMA gather)
 
-ops.py: jit'd wrappers (native on TPU, interpret-mode/ref elsewhere).
+ops.py: jit'd wrappers; the caller picks kernel or oracle (``impl=``) and
+        native or interpreted Pallas (``interpret=``).
 ref.py: pure-jnp oracles for the allclose tests.
 """
 

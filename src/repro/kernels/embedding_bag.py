@@ -7,6 +7,13 @@ the analogue of the GPU's per-warp gather, adapted to the explicit-DMA TPU
 memory hierarchy (no hardware gather on the vector unit).
 
 tables: (T, R, E); indices: (B, T, L) int32 -> out: (B, T, E).
+
+Not on the normal path, and tested in interpret mode only. The TPU compiler
+refuses it natively (compiled for a described v5e): the index block
+``(1, 1, L)`` breaks the rule that a block's last two dimensions be
+divisible by 8 and 128 (or span the array). Its design also maps a whole
+``(1, R, E)`` table into VMEM per grid step, so no deployment-sized table
+fits; a row-DMA gather from HBM has to replace it.
 """
 
 from __future__ import annotations
@@ -23,10 +30,8 @@ def _bag_kernel(idx_ref, table_ref, o_ref):
         row = idx_ref[0, 0, i]
         # Index the leading (blocked) dim with a length-1 dslice too: a bare
         # int here trips pallas' load discharge rule (no .shape on int).
-        return acc + pl.load(
-            table_ref,
-            (pl.dslice(0, 1), pl.dslice(row, 1), slice(None)))[0, 0].astype(
-                jnp.float32)
+        rows = table_ref[pl.dslice(0, 1), pl.dslice(row, 1), :]
+        return acc + rows[0, 0].astype(jnp.float32)
 
     e = table_ref.shape[-1]
     acc = jax.lax.fori_loop(0, lpool, body,

@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On TPU the kernels lower natively; everywhere else (this CPU container, the
-dry-run) they run in ``interpret=True`` mode or fall back to the jnp oracle.
-``use_pallas()`` picks the default; every op takes an explicit override.
+The caller chooses the implementation: ``impl="pallas"`` runs the kernel
+(natively on a TPU, or in Pallas' interpreter with ``interpret=True``) and
+``impl="ref"`` runs the jnp oracle. Nothing here looks at the platform, so
+a run on the wrong device fails instead of quietly measuring another path.
 
 The model code calls these through ``repro.models`` only where the fusion
 matters (attention inner loop, SSD scan); see DESIGN.md §Kernels for the
@@ -12,7 +13,6 @@ integration policy.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 
@@ -23,50 +23,45 @@ from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_kernel
 from repro.kernels.ssd_scan import ssd_scan as _ssd_kernel
 
 
-def on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
+def _check_impl(impl: str) -> None:
+    if impl not in ("pallas", "ref"):
+        raise ValueError(f"impl must be 'pallas' or 'ref', got {impl!r}")
 
 
-def use_pallas() -> bool:
-    """Native Pallas on TPU; interpret-mode Pallas elsewhere is opt-in
-    (slow on CPU — tests enable it explicitly)."""
-    return on_tpu()
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "impl"))
+@functools.partial(jax.jit, static_argnames=("causal", "impl", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True,
-                    impl: Optional[str] = None) -> jax.Array:
+                    causal: bool = True, *, impl: str,
+                    interpret: bool = False) -> jax.Array:
     """q: (b, h, sq, d), k/v: (b, hkv, skv, d)."""
-    impl = impl or ("pallas" if use_pallas() else "ref")
+    _check_impl(impl)
     if impl == "pallas":
         return flash_attention_fwd(q, k, v, causal=causal,
-                                   interpret=not on_tpu())
+                                   interpret=interpret)
     return ref.attention_ref(q, k, v, causal=causal)
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
+@functools.partial(jax.jit, static_argnames=("impl", "interpret"))
 def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, bmat: jax.Array,
-        cmat: jax.Array, impl: Optional[str] = None):
-    impl = impl or ("pallas" if use_pallas() else "ref")
+        cmat: jax.Array, *, impl: str, interpret: bool = False):
+    _check_impl(impl)
     if impl == "pallas":
-        return _ssd_kernel(x, dt, a, bmat, cmat, interpret=not on_tpu())
+        return _ssd_kernel(x, dt, a, bmat, cmat, interpret=interpret)
     return ref.ssd_ref(x, dt, a, bmat, cmat)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "impl"))
-def rmsnorm(x: jax.Array, gamma: jax.Array, eps: float = 1e-5,
-            impl: Optional[str] = None) -> jax.Array:
-    impl = impl or ("pallas" if use_pallas() else "ref")
+@functools.partial(jax.jit, static_argnames=("eps", "impl", "interpret"))
+def rmsnorm(x: jax.Array, gamma: jax.Array, eps: float = 1e-5, *,
+            impl: str, interpret: bool = False) -> jax.Array:
+    _check_impl(impl)
     if impl == "pallas":
-        return _rmsnorm_kernel(x, gamma, eps=eps, interpret=not on_tpu())
+        return _rmsnorm_kernel(x, gamma, eps=eps, interpret=interpret)
     return ref.rmsnorm_ref(x, gamma, eps)
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
-def embedding_bag(tables: jax.Array, indices: jax.Array,
-                  impl: Optional[str] = None) -> jax.Array:
-    impl = impl or ("pallas" if use_pallas() else "ref")
+@functools.partial(jax.jit, static_argnames=("impl", "interpret"))
+def embedding_bag(tables: jax.Array, indices: jax.Array, *, impl: str,
+                  interpret: bool = False) -> jax.Array:
+    _check_impl(impl)
     if impl == "pallas":
-        return _bag_kernel(tables, indices, interpret=not on_tpu())
+        return _bag_kernel(tables, indices, interpret=interpret)
     return ref.embedding_bag_ref(tables, indices)

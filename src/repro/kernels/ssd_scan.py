@@ -12,6 +12,13 @@ kernel is numerically stable without rescaling.
 
 Layout: x (b, h, s, p), dt (b, h, s), A (h,), Bmat/Cmat (b, h, s, n)
         -> y (b, h, s, p), final_state (b, h, p, n).
+
+Not on the normal path, and tested in interpret mode only. The TPU compiler
+refuses it natively at mamba2-780m widths (48 heads, chunk 256, compiled
+for a described v5e): the ``dt`` block ``(1, 1, chunk)`` breaks the rule
+that a block's last two dimensions be divisible by 8 and 128 (or span the
+array), and the ``(1,)`` block of ``A`` is of the same kind. Redesign the
+blocking before putting the kernel on the path.
 """
 
 from __future__ import annotations

@@ -1,5 +1,6 @@
 """Launchers: production mesh, multi-pod dry-run, train/serve drivers.
 
-NOTE: importing repro.launch.dryrun sets XLA_FLAGS (512 host devices) as its
-first statement — import it only in dedicated processes, never from tests.
+Importing any of these modules sets no environment variable and touches no
+device: ``dryrun.main`` sets ``XLA_FLAGS`` (512 host devices) itself, so
+only a process that runs the dry-run gets the fake CPU devices.
 """

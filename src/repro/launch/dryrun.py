@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this script:
@@ -16,10 +13,14 @@ For each cell this script:
 Usage:
   python -m repro.launch.dryrun --arch internlm2-20b --shape train_4k
   python -m repro.launch.dryrun --all [--multi-pod] [--out DIR]
+
+``main`` gives XLA's CPU backend 512 host devices (``HOST_DEVICES_FLAG``)
+before the first device query; importing this module changes nothing.
 """
 
 import argparse
 import json
+import os
 import time
 import traceback
 from typing import Dict, Optional
@@ -47,6 +48,8 @@ from repro.parallel import (
 )
 from repro.train.train_step import jit_train_step
 from repro.train.optimizer import AdamWConfig
+
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
 
 
 def _abstract_state(cfg, plan):
@@ -206,6 +209,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    os.environ["XLA_FLAGS"] = HOST_DEVICES_FLAG
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

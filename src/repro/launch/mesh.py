@@ -7,7 +7,7 @@ jax initialization.
 
 from __future__ import annotations
 
-import jax
+from repro.parallel.mesh import build_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,9 +15,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods = 512 chips as (pod=2, data=16, model=16)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return build_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small host-device mesh for tests (XLA_FLAGS device_count >= d*m)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return build_mesh((data, model), ("data", "model"))

@@ -2,23 +2,30 @@
 
     python -m repro.launch.serve --arch smollm-135m --reduced \
         --num-requests 8 --max-new-tokens 16
+
+Parameters are fp32, made from ``--seed``; prompts are random tokens with
+lengths drawn uniformly from ``--prompt-len MIN MAX`` (MAX exclusive).
 """
 
 from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import get_model
 from repro.serve import Engine, EngineConfig, Request
 
 
-def main():
+def main(argv: Optional[List[str]] = None) -> List[Request]:
+    """Serve the requests to completion; returns them in finishing order."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--reduced", action="store_true")
@@ -26,8 +33,10 @@ def main():
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--max-new-tokens", type=int, default=16)
+    ap.add_argument("--prompt-len", type=int, nargs=2, default=(4, 24),
+                    metavar=("MIN", "MAX"))
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch, reduced=args.reduced)
     model = get_model(cfg)
@@ -40,7 +49,7 @@ def main():
     rs = np.random.RandomState(args.seed)
     t0 = time.monotonic()
     for i in range(args.num_requests):
-        plen = int(rs.randint(4, 24))
+        plen = int(rs.randint(*args.prompt_len))
         prompt = rs.randint(0, cfg.vocab_size, size=plen).astype(np.int32)
         engine.submit(Request(uid=i, prompt=prompt,
                               max_new_tokens=args.max_new_tokens))
@@ -52,6 +61,7 @@ def main():
     for r in sorted(done, key=lambda r: r.uid)[:4]:
         print(f"  req {r.uid}: prompt[:4]={list(r.prompt[:4])} "
               f"out[:8]={r.out_tokens[:8]}")
+    return done
 
 
 if __name__ == "__main__":

@@ -13,14 +13,18 @@ from __future__ import annotations
 from typing import Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 DATA_AXES: Tuple[str, ...] = ("pod", "data")
 MODEL_AXIS = "model"
 
 
 def build_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings and
+    ``with_sharding_constraint`` may name any axis (``jax.make_mesh``
+    defaults to ``Explicit`` axes, which reject both)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

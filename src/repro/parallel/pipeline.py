@@ -17,7 +17,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 PIPE_AXIS = "pipe"
 
@@ -55,11 +54,11 @@ def gpipe(
             jnp.where(idx == s - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),       # params sharded by stage, x replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)
 

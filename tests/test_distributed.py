@@ -147,7 +147,6 @@ def test_compressed_psum_accuracy():
     check(run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.parallel import build_mesh
         from repro.parallel.compression import compressed_psum
         mesh = build_mesh((8,), ("data",))
@@ -156,8 +155,8 @@ def test_compressed_psum_accuracy():
         def red(x):
             s, e = compressed_psum(x, "data")
             return s
-        out = shard_map(red, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"))(g)
+        out = jax.shard_map(red, mesh=mesh, in_specs=P("data"),
+                            out_specs=P("data"))(g)
         ref = jnp.broadcast_to(g.sum(0, keepdims=True), g.shape)
         rel = float(jnp.max(jnp.abs(out - ref))) / float(jnp.max(jnp.abs(ref)))
         assert rel < 0.05, rel

@@ -125,10 +125,12 @@ class TestEmbeddingBag:
 
 
 def test_ops_dispatch():
-    """ops.py wrappers run (ref path on CPU)."""
+    """ops.py wrappers run the implementation the caller names."""
     from repro.kernels import ops
     q = jax.random.normal(KEY, (1, 2, 64, 32))
-    out = ops.flash_attention(q, q, q)
+    out = ops.flash_attention(q, q, q, impl="ref")
     assert out.shape == q.shape
     g = jnp.ones((32,))
-    assert ops.rmsnorm(q, g).shape == q.shape
+    assert ops.rmsnorm(q, g, impl="ref").shape == q.shape
+    with pytest.raises(ValueError):
+        ops.rmsnorm(q, g, impl="auto")

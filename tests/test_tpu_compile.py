@@ -1,0 +1,131 @@
+"""Compile the main path for a described, unattached TPU v5e chip.
+
+Nothing runs: the TPU compiler that ships with JAX compiles each program
+for one chip of a ``v5e:2x2`` topology and refuses what the chip would
+refuse (tiling, VMEM, device memory). Shapes are smollm-135m at full
+width, as ``chip_smoke.py`` runs it. The topology is described inside a
+fixture, never at import, and the tests skip where it cannot be described.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.rmsnorm import rmsnorm
+from repro.models import get_model
+from repro.parallel import plan_memory
+from repro.train import AdamWConfig, init_train_state, make_train_step
+
+ARCH = "smollm-135m"
+TRAIN_BATCH, TRAIN_SEQ = 4, 2048     # chip_smoke.py's train phase
+HBM_BUDGET = 14e9                    # of the chip's 16 GiB
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_compile_cache):
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return get_config(ARCH)
+
+
+def _on(sharding, build):
+    """Abstract stand-ins, placed on ``sharding``, for what ``build()``
+    returns; nothing is allocated."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(build))
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_train_step_fits_one_chip(one_chip, cfg):
+    """The launcher's step (bf16 params, fp32 master and Adam, donated
+    state) at the smoke's batch x sequence length."""
+    plan = plan_memory(cfg, tp=1, dp=1)
+    opt_cfg = AdamWConfig(state_dtype=plan.opt_dtype,
+                          use_master=plan.use_master)
+    state = _on(one_chip, lambda: init_train_state(
+        cfg, plan, jax.random.PRNGKey(0), opt_cfg))
+    tokens = _sds(one_chip, (TRAIN_BATCH, TRAIN_SEQ), jnp.int32)
+    rng = _sds(one_chip, (2,), jnp.uint32)
+    compiled = jax.jit(make_train_step(cfg, plan, opt_cfg),
+                       donate_argnums=(0,)).lower(
+        state, {"tokens": tokens, "targets": tokens}, rng).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < HBM_BUDGET, (mem.argument_size_in_bytes,
+                               mem.temp_size_in_bytes)
+
+
+def test_decode_step(one_chip, cfg):
+    """The serve engine's decode step: fp32, batch 8, 1024-deep cache."""
+    model = get_model(cfg)
+    params = _on(one_chip, lambda: model.init_params(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.float32))
+    cache = _on(one_chip, lambda: model.init_cache(cfg, 8, 1024,
+                                                   dtype=jnp.float32))
+    tokens = _sds(one_chip, (8, 1), jnp.int32)
+    compiled = jax.jit(lambda p, c, t: model.decode_step(p, cfg, c, t)).lower(
+        params, cache, tokens).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BUDGET
+
+
+def test_prefill(one_chip, cfg):
+    """One 512-token prompt into a fresh 1024-deep single-sequence cache."""
+    model = get_model(cfg)
+    params = _on(one_chip, lambda: model.init_params(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.float32))
+    cache = _on(one_chip, lambda: model.init_cache(cfg, 1, 1024,
+                                                   dtype=jnp.float32))
+    tokens = _sds(one_chip, (1, 512), jnp.int32)
+    compiled = jax.jit(lambda p, t, c: model.prefill(p, cfg, t, c)).lower(
+        params, tokens, cache).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BUDGET
+
+
+def test_flash_attention_native(one_chip, cfg):
+    hd = cfg.resolved_head_dim
+    q = _sds(one_chip, (1, cfg.num_heads, TRAIN_SEQ, hd), jnp.bfloat16)
+    kv = _sds(one_chip, (1, cfg.num_kv_heads, TRAIN_SEQ, hd), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v: flash_attention_fwd(
+        q, k, v, causal=True, interpret=False)).lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_rmsnorm_native(one_chip, cfg):
+    x = _sds(one_chip, (TRAIN_BATCH * TRAIN_SEQ, cfg.d_model), jnp.bfloat16)
+    g = _sds(one_chip, (cfg.d_model,), jnp.float32)
+    compiled = jax.jit(lambda x, g: rmsnorm(x, g, interpret=False)).lower(
+        x, g).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -7,6 +7,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint import Checkpointer, CheckpointManager
 from repro.configs import get_config
@@ -75,6 +76,21 @@ class TestCheckpointer:
             ck.save_async(9, tree)
             ck.wait()
             assert ck.latest_step() == 9
+
+    def test_async_save_error_reraised_by_wait(self, monkeypatch):
+        tree = {"a": jnp.ones((128,))}
+        with tempfile.TemporaryDirectory() as d:
+            ck = Checkpointer(d)
+
+            def fail(*args, **kwargs):
+                raise OSError("disk full")
+
+            monkeypatch.setattr(ck, "save", fail)
+            ck.save_async(3, tree)
+            with pytest.raises(OSError, match="disk full"):
+                ck.wait()
+            ck.wait()  # the error is reported once
+            assert ck.latest_step() is None
 
 
 class TestResume:
