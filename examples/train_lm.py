@@ -51,10 +51,10 @@ def main():
             total_steps=args.steps, ckpt_dir=ckpt_dir, ckpt_interval=100,
             log_interval=20))
         summary = trainer.run(rng)
+    host = ", ".join(f"{p} {t:.2f} s" for p, t in summary["host_s"].items())
     print(f"\nfinal loss {summary['final_loss']:.3f} after "
           f"{summary['final_step']} steps "
-          f"(median step {summary['median_step_s']*1e3:.0f} ms, "
-          f"stragglers: {summary['straggler_steps']})")
+          f"(host time: {host}; stragglers: {summary['straggler_steps']})")
     assert summary["final_loss"] < 7.0, "loss should drop on Markov data"
 
 

@@ -156,40 +156,40 @@ def _trunk(params: dict, cfg: ModelConfig, x: jax.Array, *,
         kc_out = []
         for j in range(me):
             sub = jax.tree.map(lambda a, j=j: a[j], lp)
-            h = rms_norm(x, sub["ln1"], cfg.norm_eps)
             kv = None
             if scanned.get("cache") is not None:
                 kv = {"k": scanned["cache"]["k"][j],
                       "v": scanned["cache"]["v"][j],
                       "pos": scanned["cache"]["pos"]}
-            attn_out, new_kv = attention_block(
-                sub["attn"], h,
-                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-                head_dim=hd, rope_fraction=cfg.rope_fraction,
-                rope_theta=cfg.rope_theta, causal=True,
-                positions=positions, kv_cache=kv,
-                batch_shard=cfg.attn_batch_shard)
-            attn_out = checkpoint_name(
-                attn_out, "block_out")
-            x = x + attn_out
-            h = rms_norm(x, sub["ln2"], cfg.norm_eps)
-            is_moe = moe_cfg is not None and j == me - 1
-            if is_moe:
-                mp = scanned["moe"]
+            # A sublayer's scope holds its pre-norm and its residual add,
+            # so that XLA's fusions of either keep the sublayer's name.
+            with jax.named_scope("attention"):
+                h = rms_norm(x, sub["ln1"], cfg.norm_eps)
+                attn_out, new_kv = attention_block(
+                    sub["attn"], h,
+                    num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                    head_dim=hd, rope_fraction=cfg.rope_fraction,
+                    rope_theta=cfg.rope_theta, causal=True,
+                    positions=positions, kv_cache=kv,
+                    batch_shard=cfg.attn_batch_shard)
+                x = x + checkpoint_name(attn_out, "block_out")
+            if moe_cfg is not None and j == me - 1:
+                h = rms_norm(x, sub["ln2"], cfg.norm_eps)
                 y, aux = moe_block(
-                    mp, h, top_k=moe_cfg.top_k,
+                    scanned["moe"], h, top_k=moe_cfg.top_k,
                     capacity_factor=moe_cfg.capacity_factor,
                     activation=cfg.activation,
                     aux_loss_weight=moe_cfg.aux_loss_weight,
                     dispatch=moe_cfg.dispatch)
                 aux_total = aux_total + aux
+                x = x + checkpoint_name(y, "block_out")
             else:
-                dp_idx = j if moe_cfg is not None else j
-                fp = jax.tree.map(lambda a: a[dp_idx], scanned["dense"]) \
-                    if scanned.get("dense") is not None else None
-                y = ffn_block(fp, h, cfg.activation)
-            y = checkpoint_name(y, "block_out")
-            x = x + y
+                with jax.named_scope("ffn"):
+                    fp = jax.tree.map(lambda a: a[j], scanned["dense"]) \
+                        if scanned.get("dense") is not None else None
+                    h = rms_norm(x, sub["ln2"], cfg.norm_eps)
+                    y = ffn_block(fp, h, cfg.activation)
+                    x = x + checkpoint_name(y, "block_out")
             if new_kv is not None:
                 kc_out.append(new_kv)
         new_cache = None
@@ -247,11 +247,12 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
         x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
     x, aux, new_cache = _trunk(params, cfg, x, positions=None, cache=cache,
                                remat=remat)
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = params.get("head")
-    if head is None:
-        head = params["embed"].T
-    logits = x @ head
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        head = params.get("head")
+        if head is None:
+            head = params["embed"].T
+        logits = x @ head
     return logits, aux, new_cache
 
 
@@ -260,8 +261,8 @@ def loss(params: dict, cfg: ModelConfig, batch: dict,
     logits, aux, _ = forward(params, cfg, batch["tokens"],
                              patches=batch.get("patches"), remat=remat)
     n_patch = 0 if batch.get("patches") is None else batch["patches"].shape[1]
-    logits = logits[:, n_patch:, :]
-    ce = cross_entropy_loss(logits, batch["targets"])
+    with jax.named_scope("head"):
+        ce = cross_entropy_loss(logits[:, n_patch:, :], batch["targets"])
     total = ce + aux
     return total, {"ce": ce, "aux": aux}
 

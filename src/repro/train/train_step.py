@@ -28,6 +28,12 @@ from repro.parallel.sharding import batch_shardings, param_shardings
 from repro.parallel.zero import opt_state_shardings
 from repro.train.optimizer import AdamWConfig, apply_updates, init_state
 
+# The ``jax.named_scope`` names that the step's sublayers carry into the
+# compiled HLO's ``op_name`` metadata: the model's attention, dense FFN and
+# head (``models/transformer.py``), and the optimizer update below. A trace
+# of the step attributes device time to them through that metadata.
+SCOPES = ("attention", "ffn", "head", "optimizer")
+
 
 def make_train_step(cfg: ModelConfig, plan: MemoryPlan,
                     opt_cfg: Optional[AdamWConfig] = None,
@@ -57,7 +63,8 @@ def make_train_step(cfg: ModelConfig, plan: MemoryPlan,
         if m <= 1:
             (loss, parts), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, _constrain_batch(batch))
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            with jax.named_scope("optimizer"):
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         else:
             mbatch = jax.tree.map(
                 lambda x: x.reshape((m, x.shape[0] // m) + x.shape[1:]),
@@ -84,8 +91,9 @@ def make_train_step(cfg: ModelConfig, plan: MemoryPlan,
             grads = jax.tree.map(
                 lambda g, s: jax.lax.with_sharding_constraint(g, s),
                 grads, grad_shardings)
-        new_params, new_opt, opt_metrics = apply_updates(
-            params, grads, state["opt"], opt_cfg, rng)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = apply_updates(
+                params, grads, state["opt"], opt_cfg, rng)
         metrics = {"loss": loss, **parts, **opt_metrics}
         return {"params": new_params, "opt": new_opt}, metrics
 

@@ -11,11 +11,18 @@ Production concerns handled here:
     re-shard away from slow hosts (on real multi-host topologies this is
     where you'd swap the data shard / alert the scheduler),
   * elastic restart — restoring onto a different mesh re-shards state via
-    the checkpoint layer; the data iterator re-splits the same stream.
+    the checkpoint layer; the data iterator re-splits the same stream,
+  * tracing — each step is a ``train.step`` span of the JAX profiler
+    (``StepTraceAnnotation``, its ``step_num`` the step) holding one span
+    per phase: ``train.input`` (next batch and step key), ``train.dispatch``
+    (the step call) and ``train.sync`` (fetching its metrics). The spans
+    cost microseconds when no profiler runs. The same phases are timed on
+    the host clock into ``phase_s`` whether or not one runs.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import signal
 import statistics
@@ -27,6 +34,9 @@ import numpy as np
 
 from repro.checkpoint.checkpointer import CheckpointManager
 from repro.data.pipeline import DataIterator
+
+PHASES = ("input", "dispatch", "sync")
+SPANS = ("train.step",) + tuple(f"train.{p}" for p in PHASES)
 
 
 @dataclasses.dataclass
@@ -52,7 +62,8 @@ class Trainer:
         self.on_straggler = on_straggler
         self.state_shardings = state_shardings
         self.step = 0
-        self.step_times: List[float] = []
+        self.step_times: List[float] = []   # host seconds, sum of phases
+        self.phase_s: Dict[str, List[float]] = {p: [] for p in PHASES}
         self.straggler_steps = 0
         self.metrics_log: List[Dict] = []
         self._preempted = False
@@ -91,6 +102,14 @@ class Trainer:
                 if self.on_straggler is not None:
                     self.on_straggler(self.step, dt / med)
 
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """The host span ``train.<name>``; its seconds go to ``phase_s``."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(f"train.{name}"):
+            yield
+        self.phase_s[name].append(time.perf_counter() - t0)
+
     def _checkpoint(self, force: bool = False) -> None:
         if self.manager is None:
             return
@@ -102,14 +121,21 @@ class Trainer:
         self._install_signal_handlers()
         rng = rng if rng is not None else jax.random.PRNGKey(self.cfg.seed)
         last_metrics: Dict = {}
+        first = {p: len(t) for p, t in self.phase_s.items()}
         while self.step < self.cfg.total_steps and not self._preempted:
-            batch = next(self.data)
-            step_rng = jax.random.fold_in(rng, self.step)
-            t0 = time.monotonic()
-            self.state, metrics = self.step_fn(self.state, batch, step_rng)
-            metrics = jax.tree.map(
-                lambda x: float(np.asarray(jax.device_get(x))), metrics)
-            dt = time.monotonic() - t0
+            with jax.profiler.StepTraceAnnotation("train.step",
+                                                  step_num=self.step):
+                with self._phase("input"):
+                    batch = next(self.data)
+                    step_rng = jax.random.fold_in(rng, self.step)
+                with self._phase("dispatch"):
+                    self.state, metrics = self.step_fn(self.state, batch,
+                                                       step_rng)
+                with self._phase("sync"):
+                    metrics = jax.tree.map(
+                        lambda x: float(np.asarray(jax.device_get(x))),
+                        metrics)
+            dt = sum(self.phase_s[p][-1] for p in PHASES)
             self._watchdog(dt)
             self.step += 1
             if self.step % self.cfg.log_interval == 0 or \
@@ -129,7 +155,7 @@ class Trainer:
             "final_step": self.step,
             "preempted": self._preempted,
             "straggler_steps": self.straggler_steps,
-            "median_step_s": (statistics.median(self.step_times)
-                              if self.step_times else 0.0),
+            "host_s": {p: sum(t[first[p]:])
+                       for p, t in self.phase_s.items()},
             **{f"final_{k}": v for k, v in last_metrics.items()},
         }

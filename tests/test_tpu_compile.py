@@ -7,6 +7,9 @@ width, as ``chip_smoke.py`` runs it. The topology is described inside a
 fixture, never at import, and the tests skip where it cannot be described.
 """
 
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -18,6 +21,11 @@ from repro.kernels.rmsnorm import rmsnorm
 from repro.models import get_model
 from repro.parallel import plan_memory
 from repro.train import AdamWConfig, init_train_state, make_train_step
+from repro.train.train_step import SCOPES
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks", "chip"))
+import scopes  # noqa: E402  (the chip benchmark's HLO reader)
 
 ARCH = "smollm-135m"
 TRAIN_BATCH, TRAIN_SEQ = 4, 2048     # chip_smoke.py's train phase
@@ -69,9 +77,10 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_train_step_fits_one_chip(one_chip, cfg):
+@pytest.fixture(scope="module")
+def train_step(one_chip, cfg):
     """The launcher's step (bf16 params, fp32 master and Adam, donated
-    state) at the smoke's batch x sequence length."""
+    state) at the smoke's batch x sequence length, compiled."""
     plan = plan_memory(cfg, tp=1, dp=1)
     opt_cfg = AdamWConfig(state_dtype=plan.opt_dtype,
                           use_master=plan.use_master)
@@ -79,13 +88,30 @@ def test_train_step_fits_one_chip(one_chip, cfg):
         cfg, plan, jax.random.PRNGKey(0), opt_cfg))
     tokens = _sds(one_chip, (TRAIN_BATCH, TRAIN_SEQ), jnp.int32)
     rng = _sds(one_chip, (2,), jnp.uint32)
-    compiled = jax.jit(make_train_step(cfg, plan, opt_cfg),
-                       donate_argnums=(0,)).lower(
+    return jax.jit(make_train_step(cfg, plan, opt_cfg),
+                   donate_argnums=(0,)).lower(
         state, {"tokens": tokens, "targets": tokens}, rng).compile()
-    mem = compiled.memory_analysis()
+
+
+def test_train_step_fits_one_chip(train_step):
+    mem = train_step.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BUDGET, (mem.argument_size_in_bytes,
                                mem.temp_size_in_bytes)
+
+
+def test_train_step_matmuls_have_scopes(train_step):
+    """Every top-level instruction that is or holds a matmul carries one
+    of the step's scopes, so a trace puts its device time on a sublayer."""
+    hlo = train_step.as_text()
+    top = scopes.top_level(hlo)
+    named = scopes.op_scopes(hlo, SCOPES)
+    missing = sorted(n for n, matmul in top.items()
+                     if matmul and n not in named)
+    unnamed = sum(n not in named for n in top)
+    assert any(top.values()) and not missing, (
+        f"matmuls without a scope: {missing}; {unnamed} of {len(top)} "
+        f"top-level instructions ({unnamed / len(top):.0%}) carry none")
 
 
 def test_decode_step(one_chip, cfg):

@@ -1,6 +1,8 @@
 """Training infrastructure: checkpointing, resume determinism, retention,
-data pipeline state, straggler watchdog, optimizer numerics."""
+data pipeline state, straggler watchdog, the trainer's spans and phase
+timers, optimizer numerics."""
 
+import glob
 import os
 import tempfile
 
@@ -21,6 +23,7 @@ from repro.train import (
     make_train_step,
 )
 from repro.train.optimizer import apply_updates, init_state
+from repro.train.trainer import PHASES, SPANS
 
 KEY = jax.random.PRNGKey(0)
 
@@ -130,6 +133,48 @@ class TestWatchdog:
         t._watchdog(0.5)
         assert t.straggler_steps == 1
         assert events and events[0] > 3
+
+
+class TestTracing:
+    def test_step_spans_in_profiler_trace(self, tmp_path):
+        """One ``train.step`` span per step, numbered by the step, holding
+        one span of each phase."""
+        from jax.profiler import ProfileData
+        t = _setup(steps=2)
+        t.run()                          # compiles outside the trace
+        t.cfg.total_steps = 5
+        with jax.profiler.trace(str(tmp_path)):
+            t.run()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        spans = {name: [] for name in SPANS}
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in spans:
+                        spans[e.name].append((e.start_ns,
+                                              e.start_ns + e.duration_ns,
+                                              dict(e.stats)))
+        steps = sorted(spans["train.step"])
+        assert [s[2]["step_num"] for s in steps] == [2, 3, 4]
+        for name in SPANS[1:]:
+            assert len(spans[name]) == 3, name
+            for (s, e, _), (ps, pe, _) in zip(sorted(spans[name]), steps):
+                assert ps <= s <= e <= pe, name
+
+    def test_phase_seconds_make_the_step_times(self):
+        t = _setup(steps=4)
+        first = t.run()
+        assert {p: len(v) for p, v in t.phase_s.items()} == \
+            {p: 4 for p in PHASES}
+        assert t.step_times == [sum(t.phase_s[p][i] for p in PHASES)
+                                for i in range(4)]
+        assert first["host_s"] == {p: sum(v) for p, v in t.phase_s.items()}
+        t.cfg.total_steps = 6
+        second = t.run()                 # this run's steps alone
+        assert second["host_s"] == {p: sum(v[4:])
+                                    for p, v in t.phase_s.items()}
+        assert "median_step_s" not in second
 
 
 class TestOptimizer:
