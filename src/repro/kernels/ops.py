@@ -5,9 +5,10 @@ The caller chooses the implementation: ``impl="pallas"`` runs the kernel
 ``impl="ref"`` runs the jnp oracle. Nothing here looks at the platform, so
 a run on the wrong device fails instead of quietly measuring another path.
 
-The model code calls these through ``repro.models`` only where the fusion
-matters (attention inner loop, SSD scan); see DESIGN.md §Kernels for the
-integration policy.
+The models do not go through these wrappers: ``models.common.attention``
+calls the differentiable flash kernel itself, where it is lowered for a TPU
+(forward and backward on the train path). The SSD scan, RMSNorm and
+embedding-bag kernels are on no model's path.
 """
 
 from __future__ import annotations
@@ -18,7 +19,7 @@ import jax
 
 from repro.kernels import ref
 from repro.kernels.embedding_bag import embedding_bag as _bag_kernel
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import flash_attention as _flash_kernel
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_kernel
 from repro.kernels.ssd_scan import ssd_scan as _ssd_kernel
 
@@ -35,8 +36,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """q: (b, h, sq, d), k/v: (b, hkv, skv, d)."""
     _check_impl(impl)
     if impl == "pallas":
-        return flash_attention_fwd(q, k, v, causal=causal,
-                                   interpret=interpret)
+        return _flash_kernel(q, k, v, causal, interpret=interpret)
     return ref.attention_ref(q, k, v, causal=causal)
 
 

@@ -5,9 +5,11 @@ Everything is functional JAX: parameters are nested dicts of jnp arrays,
 layers are pure functions. Layer stacks use stacked parameters + lax.scan so
 the lowered HLO stays O(1) in depth (compile time matters at 512 devices).
 
-The blockwise attention here is the pure-JAX (flash-style) algorithm that the
-Pallas kernel in ``repro.kernels.flash_attention`` implements on-chip; on CPU
-and in the dry-run the models run this path (see DESIGN.md §Kernels).
+Self-attention without a cache (``attention``) runs the Pallas flash kernel
+of ``repro.kernels.flash_attention``, forward and backward, when it is
+lowered for a TPU; on every other backend, and in the dry-run, it runs the
+XLA path here (naive, or the pure-JAX blockwise algorithm that the kernel
+implements on-chip, for long sequences).
 """
 
 from __future__ import annotations
@@ -17,6 +19,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax._src import mesh as mesh_lib
+
+from repro.kernels.flash_attention import flash_attention
 
 DEFAULT_DTYPE = jnp.bfloat16
 
@@ -25,6 +30,8 @@ DEFAULT_DTYPE = jnp.bfloat16
 BLOCKWISE_THRESHOLD = 4096
 Q_BLOCK = 1024
 KV_BLOCK = 1024
+# Head dims the Pallas flash kernel is compiled for (tests/test_tpu_compile.py).
+FLASH_HEAD_DIMS = (64, 128)
 
 
 # --------------------------------------------------------------------- #
@@ -193,11 +200,34 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out[:, :sq].transpose(0, 1, 2, 3)
 
 
+def _sharded() -> bool:
+    """Whether a mesh of more than one device is in context. A
+    ``pallas_call`` under GSPMD runs replicated, so the kernel stays off."""
+    return (jax.sharding.get_abstract_mesh().size > 1
+            or mesh_lib.thread_resources.env.physical_mesh.size > 1)
+
+
 def attention(q, k, v, causal=True, q_offset: int = 0):
-    """Dispatch: blockwise for long sequences, naive otherwise."""
-    if q.shape[1] >= BLOCKWISE_THRESHOLD and q.shape[1] == k.shape[1]:
-        return blockwise_attention(q, k, v, causal=causal)
-    return naive_attention(q, k, v, causal=causal, q_offset=q_offset)
+    """q: (b, sq, h, d), k/v: (b, skv, h_kv, d).
+
+    Self-attention (``sq == skv``, no offset) at a head dim the kernel
+    tiles, on one device, runs the Pallas flash kernel where it is lowered
+    for a TPU; everywhere else, and for every other call, XLA's path:
+    blockwise for long sequences, naive otherwise."""
+    def xla(q, k, v):
+        if q.shape[1] >= BLOCKWISE_THRESHOLD and q.shape[1] == k.shape[1]:
+            return blockwise_attention(q, k, v, causal=causal)
+        return naive_attention(q, k, v, causal=causal, q_offset=q_offset)
+
+    def tpu(q, k, v):
+        out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                              v.transpose(0, 2, 1, 3), causal)
+        return out.transpose(0, 2, 1, 3)
+
+    if (q.shape[1] != k.shape[1] or q_offset != 0
+            or q.shape[-1] not in FLASH_HEAD_DIMS or _sharded()):
+        return xla(q, k, v)
+    return jax.lax.platform_dependent(q, k, v, tpu=tpu, default=xla)
 
 
 # --------------------------------------------------------------------- #

@@ -7,40 +7,86 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.embedding_bag import embedding_bag
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import (Blocks, flash_attention,
+                                          flash_attention_fwd)
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssd_scan import ssd_scan
 
 KEY = jax.random.PRNGKey(42)
 
 
+FLASH_CASES = [
+    (2, 4, 2, 256, 64, True, 128, 128),
+    (1, 8, 8, 130, 32, True, 64, 64),        # ragged seq
+    (2, 2, 1, 64, 128, False, 32, 32),       # MQA, non-causal
+    (1, 4, 4, 100, 64, True, 64, 32),        # uneven blocks
+    (1, 6, 2, 96, 16, True, 32, 32),         # GQA group=3
+]
+
+
+def _qkv(b, h, hkv, s, d, dtype=jnp.float32):
+    ks = jax.random.split(KEY, 4)
+    return (jax.random.normal(ks[0], (b, h, s, d), jnp.float32).astype(dtype),
+            jax.random.normal(ks[1], (b, hkv, s, d), jnp.float32).astype(dtype),
+            jax.random.normal(ks[2], (b, hkv, s, d), jnp.float32).astype(dtype),
+            jax.random.normal(ks[3], (b, h, s, d), jnp.float32).astype(dtype))
+
+
 class TestFlashAttention:
-    @pytest.mark.parametrize("b,h,hkv,s,d,causal,bq,bk", [
-        (2, 4, 2, 256, 64, True, 128, 128),
-        (1, 8, 8, 130, 32, True, 64, 64),        # ragged seq
-        (2, 2, 1, 64, 128, False, 32, 32),       # MQA, non-causal
-        (1, 4, 4, 100, 64, True, 64, 32),        # uneven blocks
-        (1, 6, 2, 96, 16, True, 32, 32),         # GQA group=3
-    ])
+    @pytest.mark.parametrize("b,h,hkv,s,d,causal,bq,bk", FLASH_CASES)
     def test_matches_reference(self, b, h, hkv, s, d, causal, bq, bk):
-        ks = jax.random.split(KEY, 3)
-        q = jax.random.normal(ks[0], (b, h, s, d), jnp.float32)
-        k = jax.random.normal(ks[1], (b, hkv, s, d), jnp.float32)
-        v = jax.random.normal(ks[2], (b, hkv, s, d), jnp.float32)
-        out = flash_attention_fwd(q, k, v, causal=causal, block_q=bq,
-                                  block_k=bk, interpret=True)
+        """Output, and the logsumexp of each query row that the backward
+        reads, against the reference's scores."""
+        q, k, v, _ = _qkv(b, h, hkv, s, d)
+        out, lse = flash_attention_fwd(q, k, v, causal=causal, block_q=bq,
+                                       block_k=bk, interpret=True)
         want = ref.attention_ref(q, k, v, causal=causal)
         np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q,
+                            jnp.repeat(k, h // hkv, axis=1)) / np.sqrt(d)
+        if causal:
+            scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores,
+                               -jnp.inf)
+        np.testing.assert_allclose(lse, jax.nn.logsumexp(scores, axis=-1),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("b,h,hkv,s,d,causal,bq,bk", FLASH_CASES)
+    def test_gradients_match_reference(self, b, h, hkv, s, d, causal, bq,
+                                       bk):
+        """The custom VJP's (dq, dk, dv) against autodiff of the
+        reference."""
+        q, k, v, dout = _qkv(b, h, hkv, s, d)
+        blocks = Blocks(bq, bk, bq, bk, bq, bk)
+        out, back = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal, blocks, True), q, k, v)
+        want, want_back = jax.vjp(lambda q, k, v: ref.attention_ref(
+            q, k, v, causal=causal), q, k, v)
+        np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+        for got, exp in zip(back(dout), want_back(dout)):
+            np.testing.assert_allclose(got, exp, atol=2e-5, rtol=2e-5)
 
     def test_bf16(self):
-        ks = jax.random.split(KEY, 3)
-        q = jax.random.normal(ks[0], (1, 2, 128, 64)).astype(jnp.bfloat16)
-        k = jax.random.normal(ks[1], (1, 2, 128, 64)).astype(jnp.bfloat16)
-        v = jax.random.normal(ks[2], (1, 2, 128, 64)).astype(jnp.bfloat16)
-        out = flash_attention_fwd(q, k, v, interpret=True)
+        q, k, v, _ = _qkv(1, 2, 2, 128, 64, jnp.bfloat16)
+        out = flash_attention(q, k, v, interpret=True)
         want = ref.attention_ref(q, k, v)
         np.testing.assert_allclose(out.astype(np.float32),
                                    want.astype(np.float32), atol=3e-2)
+
+    def test_bf16_gradients(self):
+        """bf16 operands: the kernel's gradients are as close to the
+        float32 reference's as autodiff of the bf16 reference is."""
+        q, k, v, dout = _qkv(1, 4, 2, 128, 64, jnp.bfloat16)
+        f32 = [x.astype(jnp.float32) for x in (q, k, v, dout)]
+        _, back32 = jax.vjp(ref.attention_ref, *f32[:3])
+        want = back32(f32[3])
+        _, back = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, True, Blocks(64, 64, 64, 64, 64, 64), True), q, k, v)
+        _, back16 = jax.vjp(ref.attention_ref, q, k, v)
+        for got, bf, exp in zip(back(dout), back16(dout), want):
+            err = jnp.linalg.norm(got.astype(jnp.float32) - exp)
+            err16 = jnp.linalg.norm(bf.astype(jnp.float32) - exp)
+            assert got.dtype == jnp.bfloat16
+            assert err <= 1.5 * err16 + 1e-6, (float(err), float(err16))
 
     def test_blockwise_jnp_oracle_matches_naive(self):
         """models.common.blockwise_attention is itself verified vs naive."""
@@ -53,6 +99,56 @@ class TestFlashAttention:
                                   kv_block=64)
         want = naive_attention(q, k, v, causal=True)
         np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
+
+    def test_model_attention_off_tpu_is_xla(self):
+        """Off a TPU, models.common.attention is naive_attention, forward
+        and gradient, bit for bit."""
+        from repro.models.common import attention, naive_attention
+        ks = jax.random.split(KEY, 3)
+        q = jax.random.normal(ks[0], (2, 64, 4, 64), jnp.float32)
+        k = jax.random.normal(ks[1], (2, 64, 2, 64), jnp.float32)
+        v = jax.random.normal(ks[2], (2, 64, 2, 64), jnp.float32)
+
+        def loss(f):
+            return jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(jnp.sin(f(*a))), argnums=(0, 1, 2)))
+
+        (got, got_g), (want, want_g) = (loss(attention)(q, k, v),
+                                        loss(naive_attention)(q, k, v))
+        np.testing.assert_array_equal(got, want)
+        for a, b in zip(got_g, want_g):
+            np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("sq,skv,head_dim,q_offset,mesh,kernel", [
+        (8, 8, 64, 0, None, True),
+        (8, 8, 128, 0, None, True),
+        (8, 8, 160, 0, None, False),        # a head dim the kernel skips
+        (8, 16, 64, 0, None, False),        # cross-attention
+        (8, 8, 64, 4, None, False),         # offset queries
+        (8, 8, 64, 0, (2, 2), False),       # sharded over a mesh
+    ])
+    def test_model_attention_stages_the_kernel(self, sq, skv, head_dim,
+                                               q_offset, mesh, kernel):
+        """Where models.common.attention stages the kernel (for a TPU to
+        lower): self-attention at the head dims it tiles, on one device."""
+        from jax.sharding import AbstractMesh, AxisType
+
+        from repro.models.common import attention
+        q = jnp.zeros((1, sq, 4, head_dim))
+        kv = jnp.zeros((1, skv, 2, head_dim))
+
+        def staged():
+            return str(jax.make_jaxpr(lambda q, k, v: attention(
+                q, k, v, q_offset=q_offset))(q, kv, kv))
+
+        if mesh is None:
+            text = staged()
+        else:
+            with jax.sharding.use_abstract_mesh(AbstractMesh(
+                    mesh, ("data", "model"),
+                    axis_types=(AxisType.Auto,) * 2)):
+                text = staged()
+        assert ("pallas_call" in text) == kernel
 
 
 class TestSsdScan:

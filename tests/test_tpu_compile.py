@@ -16,9 +16,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.models import get_model
+from repro.models.common import FLASH_HEAD_DIMS
 from repro.parallel import plan_memory
 from repro.train import AdamWConfig, init_train_state, make_train_step
 from repro.train.train_step import SCOPES
@@ -114,6 +115,22 @@ def test_train_step_matmuls_have_scopes(train_step):
         f"top-level instructions ({unnamed / len(top):.0%}) carry none")
 
 
+def test_train_step_attention_runs_the_kernels(train_step, cfg):
+    """The step's attention runs the flash kernel's forward (and its
+    replay under remat), dQ and dK/dV as custom calls under the
+    ``attention`` scope, and never materializes the (b, h, s, s) scores."""
+    hlo = train_step.as_text()
+    named = scopes.op_scopes(hlo, SCOPES)
+    calls = [line.split(" = ")[0].split()[-1].lstrip("%")
+             for line in hlo.splitlines()
+             if " = " in line and "tpu_custom_call" in line]
+    kernels = {c.split(".")[0] for c in calls}
+    assert kernels == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, calls
+    assert {named.get(c) for c in calls} == {"attention"}, calls
+    assert (f"[{TRAIN_BATCH},{cfg.num_heads},{TRAIN_SEQ},{TRAIN_SEQ}]"
+            not in hlo)
+
+
 def test_decode_step(one_chip, cfg):
     """The serve engine's decode step: fp32, batch 8, 1024-deep cache."""
     model = get_model(cfg)
@@ -144,9 +161,24 @@ def test_flash_attention_native(one_chip, cfg):
     hd = cfg.resolved_head_dim
     q = _sds(one_chip, (1, cfg.num_heads, TRAIN_SEQ, hd), jnp.bfloat16)
     kv = _sds(one_chip, (1, cfg.num_kv_heads, TRAIN_SEQ, hd), jnp.bfloat16)
-    compiled = jax.jit(lambda q, k, v: flash_attention_fwd(
-        q, k, v, causal=True, interpret=False)).lower(q, kv, kv).compile()
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True)).lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("head_dim", FLASH_HEAD_DIMS)
+def test_flash_attention_bwd_native(one_chip, cfg, head_dim):
+    """The backward kernels at the head dims the models send them."""
+    q = _sds(one_chip, (1, cfg.num_heads, TRAIN_SEQ, head_dim), jnp.bfloat16)
+    kv = _sds(one_chip, (1, cfg.num_kv_heads, TRAIN_SEQ, head_dim),
+              jnp.bfloat16)
+
+    def grads(q, k, v):
+        out, back = jax.vjp(flash_attention, q, k, v)
+        return back(out)
+
+    hlo = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    assert "flash_bwd_dq" in hlo and "flash_bwd_dkv" in hlo
 
 
 def test_rmsnorm_native(one_chip, cfg):
