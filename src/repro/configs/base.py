@@ -139,6 +139,15 @@ class ModelConfig:
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
     activation: str = "swiglu"     # swiglu | gelu
+    # Scalars of muP-style models (granite-3.0); each default is neutral.
+    # The embedding output and each sublayer's output before its residual
+    # add are multiplied by the first two; attention scores are scaled by
+    # ``attention_multiplier`` in place of 1/sqrt(head_dim) when it is set;
+    # the logits are divided by ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     # When num_heads % tp != 0 the sharding rules replicate attention over
     # the model axis; this knob re-shards the attention BATCH over
     # ("data","model") instead, removing the 16x redundant compute+traffic
@@ -304,7 +313,12 @@ class ModelConfig:
             rope_theta=self.rope_theta,
             rope_fraction=self.rope_fraction,
             tie_embeddings=self.tie_embeddings,
+            norm_eps=self.norm_eps,
             activation=self.activation,
+            embedding_multiplier=self.embedding_multiplier,
+            residual_multiplier=self.residual_multiplier,
+            attention_multiplier=self.attention_multiplier,
+            logits_scaling=self.logits_scaling,
             source=self.source,
             notes="reduced smoke-test variant",
         )

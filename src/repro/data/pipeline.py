@@ -11,7 +11,7 @@ structure so the LM loss is learnable (quickstart shows it dropping).
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator
+from typing import Iterator, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,13 +66,16 @@ def dlrm_batch(cfg: DataConfig, step: int,
 @dataclasses.dataclass
 class DataIterator:
     """Stateful wrapper; ``state()`` / ``restore()`` round-trip through the
-    checkpoint."""
+    checkpoint. With ``shardings`` (a tree of shardings matching a batch,
+    as ``parallel.sharding.batch_shardings`` gives) each batch is placed
+    on the mesh."""
 
     cfg: DataConfig
     step: int = 0
     shard: int = 0
     num_shards: int = 1
     kind: str = "lm"
+    shardings: Optional[dict] = None
 
     def __iter__(self) -> Iterator[dict]:
         return self
@@ -80,6 +83,8 @@ class DataIterator:
     def __next__(self) -> dict:
         fn = lm_batch if self.kind == "lm" else dlrm_batch
         batch = fn(self.cfg, self.step, self.shard, self.num_shards)
+        if self.shardings is not None:
+            batch = jax.device_put(batch, self.shardings)
         self.step += 1
         return batch
 

@@ -207,6 +207,18 @@ def _sharded() -> bool:
             or mesh_lib.thread_resources.env.physical_mesh.size > 1)
 
 
+def data_groups(batch: int) -> int:
+    """The number of data shards of the mesh in context: the product of
+    its ``pod`` and ``data`` axes (``repro.parallel.mesh.DATA_AXES``), where
+    it divides ``batch``; 1 off a mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size <= 1:
+        mesh = mesh_lib.thread_resources.env.physical_mesh
+    n = math.prod(size for name, size in mesh.shape.items()
+                  if name in ("pod", "data"))
+    return n if n > 1 and batch % n == 0 else 1
+
+
 def attention(q, k, v, causal=True, q_offset: int = 0):
     """q: (b, sq, h, d), k/v: (b, skv, h_kv, d).
 
@@ -271,6 +283,7 @@ def attention_block(
     xkv: Optional[jax.Array] = None,   # cross-attention source
     precomputed_kv: bool = False,      # kv_cache holds frozen cross K/V
     batch_shard: bool = False,         # shard batch over ("data","model")
+    q_scale: float = 1.0,              # scores scale is q_scale/sqrt(head_dim)
 ) -> Tuple[jax.Array, Optional[dict]]:
     b, s, _ = x.shape
     src = x if xkv is None else xkv
@@ -294,6 +307,8 @@ def attention_block(
         cos, sin = rope_frequencies(head_dim, rope_fraction, rope_theta, qpos)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+    if q_scale != 1.0:
+        q = q * q_scale
 
     new_cache = None
     if kv_cache is not None and not precomputed_kv and xkv is None:
@@ -380,70 +395,98 @@ def init_moe_params(key, d_model: int, d_ff: int, num_experts: int,
 def moe_block(params: dict, x: jax.Array, *, top_k: int,
               capacity_factor: float, activation: str,
               aux_loss_weight: float = 0.0,
-              dispatch: str = "gather") -> Tuple[jax.Array, jax.Array]:
+              dispatch: str = "gather",
+              groups: int = 1) -> Tuple[jax.Array, jax.Array, dict]:
     """MoE FFN. x: (b, s, d). Expert weights are stacked on a leading
     experts axis so the sharding rules can place them on the model axis
     (EP) or shard d_ff (expert-TP) — see parallel/sharding.py.
 
     dispatch="gather": capacity-based per-expert top-C token selection
-    (drops overflow). dispatch="dense": every expert on every token,
-    weighted by the combine matrix — more FLOPs but zero dispatch
+    (drops overflow). The tokens are split into ``groups`` equal groups
+    along the batch (GShard's groups: one per data shard, so that dispatch
+    stays on the shard's chips); each expert keeps the C tokens of largest
+    gate of each group, C = int(tokens per group x top_k x
+    capacity_factor / experts). dispatch="dense": every expert on every
+    token, weighted by the combine matrix — more FLOPs but zero dispatch
     collectives (the §Perf fix for fine-grained expert-TP MoEs).
-    Returns (y, aux_loss)."""
+
+    Returns (y, aux_loss, stats). ``stats`` holds the routing: ``topk``
+    (t, top_k) each token's experts; ``kept`` (groups, e, C) each expert's
+    kept tokens by their index in the group, -1 for an empty slot (gather
+    only); ``dropped`` the share of top-k choices that capacity dropped;
+    ``load_max`` the largest count of tokens routed to one expert in one
+    group over the mean count."""
     b, s, d = x.shape
     e = params["we_up"].shape[0]
     xt = x.reshape(b * s, d)
     t = b * s
-    logits = (xt.astype(jnp.float32) @ params["router"])  # (t, e)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)     # (t, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
-    # (t, e) combine matrix with only top-k nonzero
-    combine = jnp.zeros((t, e), jnp.float32).at[
-        jnp.arange(t)[:, None], gate_idx].set(gate_vals)
+    tg = t // groups
+    with jax.named_scope("router"):
+        logits = (xt.astype(jnp.float32) @ params["router"])  # (t, e)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, top_k)     # (t, k)
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
+        # (t, e) combine matrix with only top-k nonzero
+        combine = jnp.zeros((t, e), jnp.float32).at[
+            jnp.arange(t)[:, None], gate_idx].set(gate_vals)
+        # Load-balancing aux loss (Switch-style).
+        density = combine.mean(axis=0)                        # (e,)
+        aux = aux_loss_weight * e * jnp.sum(density * probs.mean(axis=0))
+        load = jnp.sum((combine > 0).reshape(groups, tg, e), axis=1)
+        stats = {"topk": gate_idx,
+                 "load_max": jnp.max(load) * e / (tg * top_k)}
 
     if dispatch == "dense":
-        cw = combine.astype(xt.dtype)                      # (t, e)
+        with jax.named_scope("experts"):
+            cw = combine.astype(xt.dtype)                      # (t, e)
+            if activation == "swiglu":
+                he = jax.nn.silu(jnp.einsum("td,edf->tef", xt,
+                                            params["we_gate"]))
+                he = he * jnp.einsum("td,edf->tef", xt, params["we_up"])
+            else:
+                he = jax.nn.gelu(jnp.einsum("td,edf->tef", xt,
+                                            params["we_up"]))
+            y = jnp.einsum("tef,te,efd->td", he, cw, params["we_down"])
+        with jax.named_scope("combine"):
+            if "shared" in params:
+                y = y + ffn_block(params["shared"], xt, activation)
+        stats["dropped"] = jnp.zeros((), jnp.float32)
+        return y.reshape(b, s, d), aux, stats
+    # Per-expert capacity selection. Single-token decode steps use exact
+    # capacity (= tokens per group) so serving never drops; full sequences
+    # use the standard capacity factor (overflow dropped, as in
+    # Switch/GShard training).
+    if s == 1:
+        cap = tg
+    else:
+        cap = max(1, int(tg * top_k * capacity_factor / e))
+        cap = min(cap, tg)
+    with jax.named_scope("dispatch"):
+        sel_val, sel_idx = jax.lax.top_k(
+            combine.reshape(groups, tg, e).transpose(0, 2, 1), cap)
+        xg = xt.reshape(groups, tg, d)
+        xe = jax.vmap(lambda xs, idx: xs[idx])(xg, sel_idx)  # (g, e, cap, d)
+        kept = sel_val > 0
+        stats["kept"] = jnp.where(kept, sel_idx, -1)
+        stats["dropped"] = 1.0 - jnp.sum(kept) / (t * top_k)
+    with jax.named_scope("experts"):
         if activation == "swiglu":
-            he = jax.nn.silu(jnp.einsum("td,edf->tef", xt,
+            he = jax.nn.silu(jnp.einsum("gecd,edf->gecf", xe,
                                         params["we_gate"]))
-            he = he * jnp.einsum("td,edf->tef", xt, params["we_up"])
+            he = he * jnp.einsum("gecd,edf->gecf", xe, params["we_up"])
         else:
-            he = jax.nn.gelu(jnp.einsum("td,edf->tef", xt,
+            he = jax.nn.gelu(jnp.einsum("gecd,edf->gecf", xe,
                                         params["we_up"]))
-        y = jnp.einsum("tef,te,efd->td", he, cw, params["we_down"])
+        ye = jnp.einsum("gecf,efd->gecd", he, params["we_down"])
+    with jax.named_scope("combine"):
+        ye = ye * sel_val[..., None].astype(ye.dtype)
+        y = jax.vmap(lambda ys, idx: jnp.zeros((tg, d), ye.dtype).at[
+            idx.reshape(-1)].add(ys.reshape(e * cap, d)))(ye, sel_idx)
+        y = y.reshape(t, d)
         if "shared" in params:
             y = y + ffn_block(params["shared"], xt, activation)
-        density = combine.mean(axis=0)
-        aux = aux_loss_weight * e * jnp.sum(density * probs.mean(axis=0))
-        return y.reshape(b, s, d), aux
-    # Per-expert capacity selection. Single-token decode steps use exact
-    # capacity (= t) so serving never drops; full sequences use the standard
-    # capacity factor (overflow dropped, as in Switch/GShard training).
-    if s == 1:
-        cap = t
-    else:
-        cap = max(1, int(t * top_k * capacity_factor / e))
-        cap = min(cap, t)
-    sel_val, sel_idx = jax.lax.top_k(combine.T, cap)      # (e, cap)
-    xe = xt[sel_idx]                                      # (e, cap, d)
-    if activation == "swiglu":
-        he = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, params["we_gate"]))
-        he = he * jnp.einsum("ecd,edf->ecf", xe, params["we_up"])
-    else:
-        he = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", xe, params["we_up"]))
-    ye = jnp.einsum("ecf,efd->ecd", he, params["we_down"])
-    ye = ye * sel_val[..., None].astype(ye.dtype)
-    y = jnp.zeros((t, d), ye.dtype).at[sel_idx.reshape(-1)].add(
-        ye.reshape(e * cap, d))
-    if "shared" in params:
-        y = y + ffn_block(params["shared"], xt, activation)
-    # Load-balancing aux loss (Switch-style).
-    density = combine.mean(axis=0)                        # (e,)
-    router_prob = probs.mean(axis=0)
-    aux = aux_loss_weight * e * jnp.sum(density * router_prob)
-    return y.reshape(b, s, d), aux
+    return y.reshape(b, s, d), aux, stats
 
 
 # --------------------------------------------------------------------- #

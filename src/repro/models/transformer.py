@@ -23,6 +23,7 @@ from repro.models.common import (
     DEFAULT_DTYPE,
     attention_block,
     cross_entropy_loss,
+    data_groups,
     dense_init,
     embed_init,
     ffn_block,
@@ -130,16 +131,30 @@ def apply_remat(fn, policy: Optional[str]):
     raise ValueError(f"unknown remat policy {policy!r}")
 
 
+def _residual(cfg: ModelConfig, y: jax.Array) -> jax.Array:
+    """A sublayer's output as it joins the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return checkpoint_name(y, "block_out")
+
+
 def _trunk(params: dict, cfg: ModelConfig, x: jax.Array, *,
            positions: Optional[jax.Array],
            cache: Optional[dict],
            remat: Optional[str] = "dots"
-           ) -> Tuple[jax.Array, Optional[dict]]:
-    """Run all layers. x: (b, s, d). cache: stacked per-layer KV or None."""
+           ) -> Tuple[jax.Array, jax.Array, Optional[dict], dict]:
+    """Run all layers. x: (b, s, d). cache: stacked per-layer KV or None.
+
+    Returns (x, aux, new_cache, routing): ``routing`` stacks each expert
+    layer's ``moe_block`` stats over the layers (empty without experts)."""
     me = _moe_every(cfg)
     nb = _n_blocks(cfg)
     hd = cfg.resolved_head_dim
     moe_cfg = cfg.moe
+    groups = data_groups(x.shape[0])
+    # Scores scale attention_multiplier in place of 1/sqrt(head_dim).
+    q_scale = (1.0 if cfg.attention_multiplier is None
+               else cfg.attention_multiplier * hd ** 0.5)
 
     layer_stack = _reshape_blocks(params["layers"], nb, me)
     if moe_cfg is not None and me > 1:
@@ -153,6 +168,7 @@ def _trunk(params: dict, cfg: ModelConfig, x: jax.Array, *,
         """One super-block of ``me`` layers; MoE at the last position."""
         lp = scanned["layers"]          # (me, ...) sub-stack
         aux_total = jnp.zeros((), jnp.float32)
+        stats = {}
         kc_out = []
         for j in range(me):
             sub = jax.tree.map(lambda a, j=j: a[j], lp)
@@ -171,39 +187,40 @@ def _trunk(params: dict, cfg: ModelConfig, x: jax.Array, *,
                     head_dim=hd, rope_fraction=cfg.rope_fraction,
                     rope_theta=cfg.rope_theta, causal=True,
                     positions=positions, kv_cache=kv,
-                    batch_shard=cfg.attn_batch_shard)
-                x = x + checkpoint_name(attn_out, "block_out")
+                    batch_shard=cfg.attn_batch_shard, q_scale=q_scale)
+                x = x + _residual(cfg, attn_out)
             if moe_cfg is not None and j == me - 1:
-                h = rms_norm(x, sub["ln2"], cfg.norm_eps)
-                y, aux = moe_block(
-                    scanned["moe"], h, top_k=moe_cfg.top_k,
-                    capacity_factor=moe_cfg.capacity_factor,
-                    activation=cfg.activation,
-                    aux_loss_weight=moe_cfg.aux_loss_weight,
-                    dispatch=moe_cfg.dispatch)
-                aux_total = aux_total + aux
-                x = x + checkpoint_name(y, "block_out")
+                with jax.named_scope("moe"):
+                    h = rms_norm(x, sub["ln2"], cfg.norm_eps)
+                    y, aux, stats = moe_block(
+                        scanned["moe"], h, top_k=moe_cfg.top_k,
+                        capacity_factor=moe_cfg.capacity_factor,
+                        activation=cfg.activation,
+                        aux_loss_weight=moe_cfg.aux_loss_weight,
+                        dispatch=moe_cfg.dispatch, groups=groups)
+                    aux_total = aux_total + aux
+                    x = x + _residual(cfg, y)
             else:
                 with jax.named_scope("ffn"):
                     fp = jax.tree.map(lambda a: a[j], scanned["dense"]) \
                         if scanned.get("dense") is not None else None
                     h = rms_norm(x, sub["ln2"], cfg.norm_eps)
                     y = ffn_block(fp, h, cfg.activation)
-                    x = x + checkpoint_name(y, "block_out")
+                    x = x + _residual(cfg, y)
             if new_kv is not None:
                 kc_out.append(new_kv)
         new_cache = None
         if kc_out:
             new_cache = {"k": jnp.stack([c["k"] for c in kc_out]),
                          "v": jnp.stack([c["v"] for c in kc_out])}
-        return x, aux_total, new_cache
+        return x, aux_total, new_cache, stats
 
     block = apply_remat(block, remat if cache is None else None)
 
     def scan_body(carry, scanned):
         x, aux = carry
-        x, aux_b, new_cache = block(x, scanned)
-        return (x, aux + aux_b), new_cache
+        x, aux_b, new_cache, stats = block(x, scanned)
+        return (x, aux + aux_b), (new_cache, stats)
 
     scanned = {"layers": layer_stack}
     if dense_stack is not None:
@@ -218,8 +235,8 @@ def _trunk(params: dict, cfg: ModelConfig, x: jax.Array, *,
             "pos": jnp.broadcast_to(cache["pos"], (nb,) + cache["pos"].shape),
         }
 
-    (x, aux), caches = jax.lax.scan(scan_body, (x, jnp.zeros((), jnp.float32)),
-                                    scanned)
+    (x, aux), (caches, routing) = jax.lax.scan(
+        scan_body, (x, jnp.zeros((), jnp.float32)), scanned)
     new_cache = None
     if caches is not None and cache is not None:
         new_cache = {
@@ -227,12 +244,35 @@ def _trunk(params: dict, cfg: ModelConfig, x: jax.Array, *,
             "v": caches["v"].reshape(cache["v"].shape),
             "pos": cache["pos"] + x.shape[1],
         }
-    return x, aux, new_cache
+    return x, aux, new_cache, routing
 
 
 # --------------------------------------------------------------------- #
 # Public API
 # --------------------------------------------------------------------- #
+
+def _forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
+             patches: Optional[jax.Array] = None,
+             cache: Optional[dict] = None,
+             remat: Optional[str] = "dots"):
+    """``forward``, and the trunk's routing."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    if patches is not None:
+        x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
+    x, aux, new_cache, routing = _trunk(params, cfg, x, positions=None,
+                                        cache=cache, remat=remat)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        head = params.get("head")
+        if head is None:
+            head = params["embed"].T
+        logits = x @ head
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+    return logits, aux, new_cache, routing
+
 
 def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
             patches: Optional[jax.Array] = None,
@@ -242,29 +282,34 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     """tokens: (b, s) int32; patches: (b, p, d) for VLM.
 
     Returns (logits, aux_loss, new_cache)."""
-    x = jnp.take(params["embed"], tokens, axis=0)
-    if patches is not None:
-        x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
-    x, aux, new_cache = _trunk(params, cfg, x, positions=None, cache=cache,
-                               remat=remat)
-    with jax.named_scope("head"):
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-        head = params.get("head")
-        if head is None:
-            head = params["embed"].T
-        logits = x @ head
-    return logits, aux, new_cache
+    return _forward(params, cfg, tokens, patches=patches, cache=cache,
+                    remat=remat)[:3]
 
 
 def loss(params: dict, cfg: ModelConfig, batch: dict,
          remat: Optional[str] = "dots") -> Tuple[jax.Array, dict]:
-    logits, aux, _ = forward(params, cfg, batch["tokens"],
-                             patches=batch.get("patches"), remat=remat)
+    """Mean cross-entropy plus the experts' auxiliary loss. The parts are
+    ``ce`` and ``aux``; with experts also ``moe_dropped_share`` (top-k
+    choices dropped by capacity, mean over layers), ``moe_load_max`` (the
+    largest tokens-per-expert over the mean, over layers and groups), and
+    the routing of every expert layer: ``route_topk`` (layers, tokens, k)
+    and, with capacity dispatch, ``route_kept`` (layers, groups, experts,
+    capacity), as ``moe_block`` gives them."""
+    logits, aux, _, routing = _forward(params, cfg, batch["tokens"],
+                                       patches=batch.get("patches"),
+                                       remat=remat)
     n_patch = 0 if batch.get("patches") is None else batch["patches"].shape[1]
     with jax.named_scope("head"):
         ce = cross_entropy_loss(logits[:, n_patch:, :], batch["targets"])
     total = ce + aux
-    return total, {"ce": ce, "aux": aux}
+    parts = {"ce": ce, "aux": aux}
+    if routing:
+        parts.update(moe_dropped_share=jnp.mean(routing["dropped"]),
+                     moe_load_max=jnp.max(routing["load_max"]),
+                     route_topk=routing["topk"])
+        if "kept" in routing:
+            parts["route_kept"] = routing["kept"]
+    return total, parts
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,

@@ -33,6 +33,13 @@ from repro.train.optimizer import AdamWConfig, apply_updates, init_state
 # head (``models/transformer.py``), and the optimizer update below. A trace
 # of the step attributes device time to them through that metadata.
 SCOPES = ("attention", "ffn", "head", "optimizer")
+# Models with experts put each expert sublayer (its pre-norm, router,
+# dispatch, experts, combine and residual add) under this scope in place of
+# ``ffn``; ``router``, ``dispatch``, ``experts`` and ``combine`` nest in it.
+MOE_SCOPE = "moe"
+# The loss's parts that are not averaged over microbatches: the largest
+# over them is kept, and arrays are stacked on a leading microbatch axis.
+_MAX_PARTS = ("moe_load_max",)
 
 
 def make_train_step(cfg: ModelConfig, plan: MemoryPlan,
@@ -76,17 +83,21 @@ def make_train_step(cfg: ModelConfig, plan: MemoryPlan,
                     loss_fn, has_aux=True)(params, _constrain_batch(mb))
                 acc_g = jax.tree.map(
                     lambda a, x: a + x.astype(acc_dtype) / m, acc_g, g)
-                acc_parts = jax.tree.map(lambda a, x: a + x / m,
-                                         acc_parts, parts)
-                return (acc_loss + mb_loss / m, acc_parts, acc_g), None
+                acc_parts = jax.tree.map(lambda a, x: a + x / m, acc_parts,
+                                         {k: parts[k] for k in acc_parts})
+                rest = {k: v for k, v in parts.items() if k not in acc_parts}
+                return (acc_loss + mb_loss / m, acc_parts, acc_g), rest
 
             zero_g = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, acc_dtype), params)
             zero_parts = {"ce": jnp.zeros((), jnp.float32),
                           "aux": jnp.zeros((), jnp.float32)}
-            (loss, parts, grads), _ = jax.lax.scan(
+            (loss, parts, grads), rest = jax.lax.scan(
                 body, (jnp.zeros((), jnp.float32), zero_parts, zero_g),
                 mbatch)
+            for k, v in rest.items():
+                parts[k] = (v if v.ndim > 1 else v.max(0) if k in _MAX_PARTS
+                            else v.mean(0))
         if grad_shardings is not None:
             grads = jax.tree.map(
                 lambda g, s: jax.lax.with_sharding_constraint(g, s),
@@ -136,10 +147,11 @@ def jit_train_step(cfg: ModelConfig, plan: MemoryPlan, mesh: Mesh,
                            grad_shardings=st_sh["opt"]["m"])
     b_sh = batch_shardings(mesh, batch_shapes, cfg)
     rng_sh = NamedSharding(mesh, P())
-    metrics_sh = NamedSharding(mesh, P())
+    # The metrics' shardings are left to the compiler: the scalars come out
+    # replicated, and the routing arrays stay where they were computed.
     return jax.jit(
         step,
         in_shardings=(st_sh, b_sh, rng_sh),
-        out_shardings=(st_sh, metrics_sh),
+        out_shardings=(st_sh, None),
         donate_argnums=(0,) if donate else (),
     )

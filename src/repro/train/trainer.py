@@ -15,9 +15,13 @@ Production concerns handled here:
   * tracing — each step is a ``train.step`` span of the JAX profiler
     (``StepTraceAnnotation``, its ``step_num`` the step) holding one span
     per phase: ``train.input`` (next batch and step key), ``train.dispatch``
-    (the step call) and ``train.sync`` (fetching its metrics). The spans
-    cost microseconds when no profiler runs. The same phases are timed on
-    the host clock into ``phase_s`` whether or not one runs.
+    (the step call) and ``train.sync`` (fetching its scalar metrics). The
+    spans cost microseconds when no profiler runs. The same phases are
+    timed on the host clock into ``phase_s`` whether or not one runs.
+
+Only the step's scalar (0-d) metrics are fetched and logged; the newest
+step's array metrics (an expert model's routing) stay on the device as
+``last_arrays``.
 """
 
 from __future__ import annotations
@@ -66,6 +70,7 @@ class Trainer:
         self.phase_s: Dict[str, List[float]] = {p: [] for p in PHASES}
         self.straggler_steps = 0
         self.metrics_log: List[Dict] = []
+        self.last_arrays: Dict[str, jax.Array] = {}
         self._preempted = False
         self.manager = (CheckpointManager(cfg.ckpt_dir, cfg.ckpt_interval,
                                           cfg.ckpt_keep)
@@ -132,9 +137,11 @@ class Trainer:
                     self.state, metrics = self.step_fn(self.state, batch,
                                                        step_rng)
                 with self._phase("sync"):
-                    metrics = jax.tree.map(
-                        lambda x: float(np.asarray(jax.device_get(x))),
-                        metrics)
+                    self.last_arrays = {k: v for k, v in metrics.items()
+                                        if np.ndim(v) > 0}
+                    metrics = {k: float(np.asarray(jax.device_get(v)))
+                               for k, v in metrics.items()
+                               if k not in self.last_arrays}
             dt = sum(self.phase_s[p][-1] for p in PHASES)
             self._watchdog(dt)
             self.step += 1

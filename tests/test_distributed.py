@@ -93,6 +93,30 @@ def test_moe_ep_equivalence():
         """))
 
 
+def test_mesh_launch_path_matches_one_device():
+    """``launch/train.py --mesh 2,2`` (EP over model, ZeRO-1 over data,
+    each data shard's tokens routed as a group) on granite's published
+    variant at a reduced size == the same steps on one device with the
+    same two routing groups."""
+    check(run_devices("""
+        import numpy as np
+        import repro.models.transformer as transformer
+        from repro.launch import train
+        args = ["--arch", "granite-3.0-3b-a800m", "--reduced", "--steps",
+                "3", "--global-batch", "4", "--seq-len", "32",
+                "--log-interval", "1"]
+        mesh = train.main(args + ["--mesh", "2,2"])
+        transformer.data_groups = lambda b: 2
+        one = train.main(args)
+        for name in ("loss", "grad_norm", "moe_dropped_share"):
+            a = [row[name] for row in mesh["metrics_log"]]
+            b = [row[name] for row in one["metrics_log"]]
+            print(name, a, b)
+            np.testing.assert_allclose(a, b, rtol=2e-3, atol=1e-6)
+        print("OK")
+        """, n=4))
+
+
 def test_zero_sharding_reduces_per_device_bytes():
     """ZeRO-1: optimizer states sharded over data -> per-device shard is
     1/dp of the full tensor."""

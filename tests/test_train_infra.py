@@ -177,6 +177,29 @@ class TestTracing:
         assert "median_step_s" not in second
 
 
+class TestArrayMetrics:
+    def test_only_scalars_are_fetched(self):
+        """Array metrics (an expert model's routing) stay on the device as
+        the newest step's ``last_arrays``; the scalars become floats."""
+        def step(state, batch, rng):
+            return state + 1, {"loss": state * 2.0,
+                               "route_topk": jnp.full((2, 3), state)}
+
+        data = DataIterator(DataConfig(vocab_size=16, seq_len=4,
+                                       global_batch=2))
+        t = Trainer(jax.jit(step), jnp.zeros((), jnp.int32), data,
+                    TrainerConfig(total_steps=3, log_interval=1))
+        out = t.run(KEY)
+        assert out["final_loss"] == 4.0 and type(out["final_loss"]) is float
+        assert not any("route_topk" in k for k in out)
+        assert [set(row) for row in t.metrics_log] == \
+            [{"step", "time_s", "loss"}] * 3
+        arr = t.last_arrays["route_topk"]
+        assert set(t.last_arrays) == {"route_topk"}
+        assert isinstance(arr, jax.Array) and arr.shape == (2, 3)
+        assert int(arr[0, 0]) == 2          # the newest step's
+
+
 class TestOptimizer:
     def test_adamw_decreases_simple_loss(self):
         w = {"w": jnp.array([2.0, -3.0])}
