@@ -7,8 +7,8 @@ a run on the wrong device fails instead of quietly measuring another path.
 
 The models do not go through these wrappers: ``models.common.attention``
 calls the differentiable flash kernel itself, where it is lowered for a TPU
-(forward and backward on the train path). The SSD scan, RMSNorm and
-embedding-bag kernels are on no model's path.
+(forward and backward on the train path; once per shard under a mesh). The
+SSD scan, RMSNorm and embedding-bag kernels are on no model's path.
 """
 
 from __future__ import annotations

@@ -7,21 +7,25 @@ the lowered HLO stays O(1) in depth (compile time matters at 512 devices).
 
 Self-attention without a cache (``attention``) runs the Pallas flash kernel
 of ``repro.kernels.flash_attention``, forward and backward, when it is
-lowered for a TPU; on every other backend, and in the dry-run, it runs the
+lowered for a TPU (under a mesh, once per (data, model) shard inside
+``shard_map``); on every other backend, and in the dry-run, it runs the
 XLA path here (naive, or the pure-JAX blockwise algorithm that the kernel
 implements on-chip, for long sequences).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax._src import mesh as mesh_lib
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import BLOCKS, Blocks, flash_attention
+from repro.parallel.mesh import DATA_AXES, MODEL_AXIS
 
 DEFAULT_DTYPE = jnp.bfloat16
 
@@ -200,45 +204,99 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out[:, :sq].transpose(0, 1, 2, 3)
 
 
-def _sharded() -> bool:
-    """Whether a mesh of more than one device is in context. A
-    ``pallas_call`` under GSPMD runs replicated, so the kernel stays off."""
-    return (jax.sharding.get_abstract_mesh().size > 1
-            or mesh_lib.thread_resources.env.physical_mesh.size > 1)
+def _mesh():
+    """The mesh in context: the abstract one (``jax.set_mesh``, or the
+    manual axes inside a ``shard_map``), else the physical one that a
+    ``with mesh:`` block enters."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size <= 1:
+        mesh = mesh_lib.thread_resources.env.physical_mesh
+    return mesh
 
 
 def data_groups(batch: int) -> int:
     """The number of data shards of the mesh in context: the product of
     its ``pod`` and ``data`` axes (``repro.parallel.mesh.DATA_AXES``), where
     it divides ``batch``; 1 off a mesh."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.size <= 1:
-        mesh = mesh_lib.thread_resources.env.physical_mesh
+    mesh = _mesh()
     n = math.prod(size for name, size in mesh.shape.items()
-                  if name in ("pod", "data"))
+                  if name in DATA_AXES)
     return n if n > 1 and batch % n == 0 else 1
+
+
+def _shard_spec(mesh, q: jax.Array, k: jax.Array) -> Optional[P]:
+    """The spec of q, k and v, (b, s, h, d), under which the flash kernel
+    runs once per shard of ``mesh``: the batch over the data axes, the
+    heads over ``model``, as the projections lay them out. None where the
+    mesh's devices are not TPUs (an abstract mesh that names no device
+    kind may be), an axis is already manual, another axis spans more than
+    one device, or the batch or either head count does not divide its
+    axes."""
+    if isinstance(mesh, jax.sharding.Mesh):
+        kind = mesh.devices.flat[0].device_kind
+    else:
+        kind = mesh.abstract_device and mesh.abstract_device.device_kind
+    if kind and not kind.startswith("TPU"):
+        return None
+    if any(t == AxisType.Manual for t in mesh.axis_types):
+        return None
+    if any(size > 1 and name not in DATA_AXES + (MODEL_AXIS,)
+           for name, size in mesh.shape.items()):
+        return None
+    data = tuple(a for a in DATA_AXES if a in mesh.shape)
+    model = mesh.shape.get(MODEL_AXIS, 1)
+    if (q.shape[0] % math.prod(mesh.shape[a] for a in data)
+            or q.shape[2] % model or k.shape[2] % model):
+        return None
+    return P(data or None, None,
+             MODEL_AXIS if MODEL_AXIS in mesh.shape else None, None)
+
+
+def _flash(q, k, v, causal: bool, blocks: Blocks = BLOCKS,
+           interpret: bool = False):
+    """The flash kernel on q, k and v in the models' (b, s, h, d) layout."""
+    out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                          v.transpose(0, 2, 1, 3), causal, blocks, interpret)
+    return out.transpose(0, 2, 1, 3)
+
+
+def flash_per_shard(mesh, spec: P, causal: bool, blocks: Blocks = BLOCKS,
+                    interpret: bool = False):
+    """``_flash`` once per shard of q, k and v laid out over ``mesh`` by
+    ``spec`` (``_shard_spec``): each device runs the kernel on its own
+    sequences and heads, with no collective. ``check_vma`` is off because
+    the kernel's outputs name no varying axes."""
+    return jax.shard_map(
+        functools.partial(_flash, causal=causal, blocks=blocks,
+                          interpret=interpret),
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
 
 
 def attention(q, k, v, causal=True, q_offset: int = 0):
     """q: (b, sq, h, d), k/v: (b, skv, h_kv, d).
 
     Self-attention (``sq == skv``, no offset) at a head dim the kernel
-    tiles, on one device, runs the Pallas flash kernel where it is lowered
-    for a TPU; everywhere else, and for every other call, XLA's path:
-    blockwise for long sequences, naive otherwise."""
+    tiles runs the Pallas flash kernel where it is lowered for a TPU: on
+    one device as it is, under a mesh once per (data, model) shard
+    (``flash_per_shard``) where ``_shard_spec`` finds one. Every other
+    call, and every other backend, takes XLA's path: blockwise for long
+    sequences, naive otherwise."""
     def xla(q, k, v):
         if q.shape[1] >= BLOCKWISE_THRESHOLD and q.shape[1] == k.shape[1]:
             return blockwise_attention(q, k, v, causal=causal)
         return naive_attention(q, k, v, causal=causal, q_offset=q_offset)
 
-    def tpu(q, k, v):
-        out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3), causal)
-        return out.transpose(0, 2, 1, 3)
-
     if (q.shape[1] != k.shape[1] or q_offset != 0
-            or q.shape[-1] not in FLASH_HEAD_DIMS or _sharded()):
+            or q.shape[-1] not in FLASH_HEAD_DIMS):
         return xla(q, k, v)
+    tpu = functools.partial(_flash, causal=causal)
+    mesh = _mesh()
+    if mesh.size > 1:
+        # A pallas_call left to GSPMD would run replicated on every device.
+        spec = _shard_spec(mesh, q, k)
+        if spec is None:
+            return xla(q, k, v)
+        tpu = flash_per_shard(mesh, spec, causal)
     return jax.lax.platform_dependent(q, k, v, tpu=tpu, default=xla)
 
 
@@ -263,7 +321,6 @@ def _batch_shard(t: jax.Array) -> jax.Array:
     """Constrain the leading (batch) dim over ("data", "model") — used when
     attention heads cannot shard over the model axis (see ModelConfig
     .attn_batch_shard)."""
-    from jax.sharding import PartitionSpec as P
     return jax.lax.with_sharding_constraint(
         t, P(("data", "model"), *([None] * (t.ndim - 1))))
 

@@ -125,12 +125,13 @@ class TestFlashAttention:
         (8, 8, 160, 0, None, False),        # a head dim the kernel skips
         (8, 16, 64, 0, None, False),        # cross-attention
         (8, 8, 64, 4, None, False),         # offset queries
-        (8, 8, 64, 0, (2, 2), False),       # sharded over a mesh
+        (8, 8, 64, 0, (2, 2), False),       # a batch of 1 over 2 data shards
     ])
     def test_model_attention_stages_the_kernel(self, sq, skv, head_dim,
                                                q_offset, mesh, kernel):
         """Where models.common.attention stages the kernel (for a TPU to
-        lower): self-attention at the head dims it tiles, on one device."""
+        lower): self-attention at the head dims it tiles, on one device or
+        per shard of a mesh that its shapes divide."""
         from jax.sharding import AbstractMesh, AxisType
 
         from repro.models.common import attention
@@ -149,6 +150,56 @@ class TestFlashAttention:
                     axis_types=(AxisType.Auto,) * 2)):
                 text = staged()
         assert ("pallas_call" in text) == kernel
+
+    @pytest.mark.parametrize("shape,names,b,h,hkv,kind,spec", [
+        ((2, 2), ("data", "model"), 4, 12, 4, "TPU v5 lite",
+         ("data", None, "model", None)),
+        ((2, 2), ("data", "model"), 4, 12, 4, None,       # kind unknown
+         ("data", None, "model", None)),
+        ((2, 2), ("data", "model"), 4, 12, 4, "cpu", None),
+        ((2, 2), ("data", "model"), 3, 12, 4, "TPU v5 lite", None),
+        ((2, 2), ("data", "model"), 4, 12, 3, "TPU v5 lite", None),
+        ((2, 2, 2), ("pod", "data", "model"), 4, 12, 4, "TPU v5 lite",
+         (("pod", "data"), None, "model", None)),
+        ((4,), ("data",), 4, 9, 3, "TPU v5 lite", ("data", None, None, None)),
+        ((4,), ("pipe",), 4, 12, 4, "TPU v5 lite", None),
+    ])
+    def test_model_attention_per_shard_spec(self, shape, names, b, h, hkv,
+                                            kind, spec):
+        """Under a mesh, models.common.attention stages the kernel inside
+        ``shard_map`` exactly where a per-shard spec exists: TPU devices (or
+        an unnamed kind), no axis but the data and model axes spanning
+        devices, and a batch and head counts that divide them."""
+        from jax._src.mesh import AbstractDevice
+        from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
+
+        from repro.models.common import _shard_spec, attention
+        mesh = AbstractMesh(shape, names, axis_types=(AxisType.Auto,) *
+                            len(names), abstract_device=kind and
+                            AbstractDevice(kind, None))
+        q = jnp.zeros((b, 128, h, 64), jnp.bfloat16)
+        kv = jnp.zeros((b, 128, hkv, 64), jnp.bfloat16)
+        assert _shard_spec(mesh, q, kv) == (spec and P(*spec))
+        with jax.sharding.use_abstract_mesh(mesh):
+            text = str(jax.make_jaxpr(attention)(q, kv, kv))
+        assert ("pallas_call" in text) == ("shard_map" in text) == bool(spec)
+
+    def test_model_attention_inside_shard_map_is_xla(self):
+        """Where the mesh's axes are already manual (inside a
+        ``shard_map``, as in the GPipe pipeline), attention keeps XLA's
+        path."""
+        from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
+
+        from repro.models.common import attention
+        mesh = AbstractMesh((2, 2), ("data", "model"),
+                            axis_types=(AxisType.Auto,) * 2)
+        q = jnp.zeros((4, 128, 12, 64), jnp.bfloat16)
+        kv = jnp.zeros((4, 128, 4, 64), jnp.bfloat16)
+        body = jax.shard_map(lambda q, k, v: attention(q, k, v), mesh=mesh,
+                             in_specs=P("data"), out_specs=P("data"))
+        with jax.sharding.use_abstract_mesh(mesh):
+            text = str(jax.make_jaxpr(body)(q, kv, kv))
+        assert "pallas_call" not in text
 
 
 class TestSsdScan:

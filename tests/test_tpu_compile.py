@@ -3,17 +3,21 @@
 Nothing runs: the TPU compiler that ships with JAX compiles each program
 for one chip of a ``v5e:2x2`` topology and refuses what the chip would
 refuse (tiling, VMEM, device memory). Shapes are smollm-135m at full
-width, as ``chip_smoke.py`` runs it. The topology is described inside a
-fixture, never at import, and the tests skip where it cannot be described.
+width, as ``chip_smoke.py`` runs it; one program, granite-3.0-3b-a800m's
+train step, is compiled for all four chips as a (2 data, 2 model) mesh.
+The topology is described inside a fixture, never at import, and the tests
+skip where it cannot be described.
 """
 
+import dataclasses
 import os
 import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
@@ -22,7 +26,7 @@ from repro.models import get_model
 from repro.models.common import FLASH_HEAD_DIMS
 from repro.parallel import plan_memory
 from repro.train import AdamWConfig, init_train_state, make_train_step
-from repro.train.train_step import SCOPES
+from repro.train.train_step import MOE_SCOPE, SCOPES, jit_train_step
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks", "chip"))
@@ -115,20 +119,55 @@ def test_train_step_matmuls_have_scopes(train_step):
         f"top-level instructions ({unnamed / len(top):.0%}) carry none")
 
 
-def test_train_step_attention_runs_the_kernels(train_step, cfg):
-    """The step's attention runs the flash kernel's forward (and its
-    replay under remat), dQ and dK/dV as custom calls under the
-    ``attention`` scope, and never materializes the (b, h, s, s) scores."""
-    hlo = train_step.as_text()
-    named = scopes.op_scopes(hlo, SCOPES)
+def _assert_flash_under_attention(hlo: str, scope_names, scores: str):
+    """The flash kernel's forward (and its replay under remat), dQ and
+    dK/dV are the program's only custom calls, all under the ``attention``
+    scope, and the ``scores`` shape of materialized scores never occurs."""
+    named = scopes.op_scopes(hlo, scope_names)
     calls = [line.split(" = ")[0].split()[-1].lstrip("%")
              for line in hlo.splitlines()
              if " = " in line and "tpu_custom_call" in line]
     kernels = {c.split(".")[0] for c in calls}
     assert kernels == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, calls
     assert {named.get(c) for c in calls} == {"attention"}, calls
-    assert (f"[{TRAIN_BATCH},{cfg.num_heads},{TRAIN_SEQ},{TRAIN_SEQ}]"
-            not in hlo)
+    assert scores not in hlo
+
+
+def test_train_step_attention_runs_the_kernels(train_step, cfg):
+    """The step's attention runs the flash kernels and never materializes
+    the (b, h, s, s) scores."""
+    _assert_flash_under_attention(
+        train_step.as_text(), SCOPES,
+        f"[{TRAIN_BATCH},{cfg.num_heads},{TRAIN_SEQ},{TRAIN_SEQ}]")
+
+
+MESH_ARCH, MESH_LAYERS = "granite-3.0-3b-a800m", 2   # 2 of 32: a quick compile
+MESH_BATCH = 8                                        # 4 sequences per data shard
+
+
+def test_mesh_train_step_attention_runs_the_kernels_per_shard(topo):
+    """granite-3.0-3b-a800m's train step at 8 x 2048 tokens on the four
+    chips as a (2 data, 2 model) mesh, as the chip benchmark's four-chip
+    cell runs it (2 of its layers): attention runs the flash kernels once
+    per shard, on 4 sequences and 12 of the 24 query heads, and no shard
+    materializes its (4, 12, 2048, 2048) scores."""
+    cfg = dataclasses.replace(get_config(MESH_ARCH), num_layers=MESH_LAYERS)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    plan = plan_memory(cfg, tp=2, dp=2)
+    opt_cfg = AdamWConfig(state_dtype=plan.opt_dtype,
+                          use_master=plan.use_master)
+    state = jax.eval_shape(lambda: init_train_state(
+        cfg, plan, jax.random.PRNGKey(0), opt_cfg))
+    tokens = jax.ShapeDtypeStruct((MESH_BATCH, TRAIN_SEQ), jnp.int32)
+    batch = {"tokens": tokens, "targets": tokens}
+    with mesh:
+        hlo = jit_train_step(cfg, plan, mesh, state, batch, opt_cfg).lower(
+            state, batch, jax.ShapeDtypeStruct((2,), jnp.uint32)
+        ).compile().as_text()
+    _assert_flash_under_attention(
+        hlo, SCOPES + (MOE_SCOPE,),
+        f"[{MESH_BATCH // 2},{cfg.num_heads // 2},{TRAIN_SEQ},{TRAIN_SEQ}]")
 
 
 def test_decode_step(one_chip, cfg):
